@@ -10,18 +10,21 @@ Chrome trace-event JSON you can open directly in Perfetto:
 
 What to look at in the UI (DESIGN.md §11):
 
-* the ``cnn-engine-dispatch`` track: ``coalesce → stage → dispatch`` spans
-  per batch — the host side of the pipeline;
+* the ``cnn-engine-dispatch`` track: ``coalesce → stage → dispatch
+  (h2d, launch) → handoff`` spans per batch — the host side of the
+  pipeline; ``handoff`` is where the dispatcher waits on the completer;
 * the ``cnn-engine-complete`` track: ``device`` (blocking on the device
   value) and ``complete`` (output scatter) spans — watch ``stage`` of
   batch *k+1* sit on top of ``device`` of batch *k*: that overlap *is* the
   double-buffered pipeline;
 * the async ``request`` track: one span per request id from submit to
-  completion, with batch id / bucket / lane stamped in the end-event args;
+  completion, with batch id / bucket / lane stamped in the end-event args
+  (recorded once per batch by the completer, not by ``submit``);
 * the ``queue_depth`` / ``batch_occupancy`` counter tracks.
 
-Also dumps the engine's metrics registry (cache hits/lowerings, batch
-occupancy, latency histogram) as ``serving_metrics.json``.
+Also dumps the engine's metrics registry (executor-cache hits/lowerings,
+prewarm time) as ``serving_metrics.json``; batch and latency accounting
+is in the run's ``ServeStats``.
 
     PYTHONPATH=src python examples/trace_serving.py [--requests N] [--out DIR]
 """
@@ -74,8 +77,8 @@ def main() -> None:
     devices = tracer.spans("device")
     stages = tracer.spans("stage")
     overlaps = sum(
-        1 for (t0, d0, _) in devices for (t1, d1, _) in stages
-        if t1 < t0 + d0 and t0 < t1 + d1
+        1 for (_, _, d0, d1, _) in devices for (_, _, s0, s1, _) in stages
+        if s0 < d1 and d0 < s1
     )
     print(f"served {run.requests} requests in {run.batches} batches "
           f"({run.qps:.0f} qps, p99 {run.latency_ms(99):.2f} ms)")

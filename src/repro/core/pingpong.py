@@ -415,6 +415,7 @@ def apply_dag_segment(
     vals: Dict[str, jax.Array],
     nbatch: int,
     *,
+    index: int,
     apply_node_fn=apply_node,
 ) -> Dict[str, jax.Array]:
     """Execute one compiled segment and return its tail values.
@@ -425,43 +426,79 @@ def apply_dag_segment(
     ``steps`` maps step name → :class:`~repro.core.schedule.Step`, ``sizes``
     maps buffer name → planned element count, ``vals`` holds the live
     buffer values the segment reads; the returned dict maps each branch
-    tail to its produced value.
+    tail to its produced value.  ``index`` is the segment's position in
+    the schedule: its ops run under ``jax.named_scope("seg<index>.<form>")``
+    so the compiled program names every segment.
     """
-    first = steps[seg.branches[0][0]]
-    # The scan body applies the segment's `period` phase layers in
-    # order (period 1: the homogeneous run).  Phase j's weights for
-    # iteration k come from branch position k·period + j, so the
-    # per-phase stack along the scan axis is names[j::period].
-    phases = [steps[n] for n in seg.branches[0][: seg.period]]
-    _apply = lambda step, p, xs: _apply_step_views(step, p, xs, apply_node_fn)
-    if seg.batched:
-        # Batched isomorphic branches: stack the B branch inputs on a
-        # new leading axis and run the whole group as one dispatch
-        # (L = 1) or one lax.scan with a batched two-bank carry
-        # (L > 1; the chain-run invariants guarantee a constant
-        # carry shape).  Weights stack to (L, B, ...) per phase.
-        xs = jnp.stack(
-            [vals[steps[br[0]].inputs[0]] for br in seg.branches]
-        )
-        if seg.length == 1:
-            per_branch = _stack_params(
-                params, [br[0] for br in seg.branches]
+    with jax.named_scope(f"seg{index}.{seg.form}"):
+        first = steps[seg.branches[0][0]]
+        # The scan body applies the segment's `period` phase layers in
+        # order (period 1: the homogeneous run).  Phase j's weights for
+        # iteration k come from branch position k·period + j, so the
+        # per-phase stack along the scan axis is names[j::period].
+        phases = [steps[n] for n in seg.branches[0][: seg.period]]
+        _apply = lambda step, p, xs: _apply_step_views(step, p, xs, apply_node_fn)
+        if seg.batched:
+            # Batched isomorphic branches: stack the B branch inputs on a
+            # new leading axis and run the whole group as one dispatch
+            # (L = 1) or one lax.scan with a batched two-bank carry
+            # (L > 1; the chain-run invariants guarantee a constant
+            # carry shape).  Weights stack to (L, B, ...) per phase.
+            xs = jnp.stack(
+                [vals[steps[br[0]].inputs[0]] for br in seg.branches]
             )
-            ys = jax.vmap(
-                lambda p, xx, _step=first: _apply(_step, p, [xx])
-            )(per_branch, xs)
-        else:
-            stacked = tuple(
-                jax.tree.map(
-                    lambda *leaves: jnp.stack(leaves),
-                    *[
-                        _stack_params(
-                            params,
-                            [br[k * seg.period + j] for br in seg.branches],
-                        )
-                        for k in range(seg.length)
-                    ],
+            if seg.length == 1:
+                per_branch = _stack_params(
+                    params, [br[0] for br in seg.branches]
                 )
+                ys = jax.vmap(
+                    lambda p, xx, _step=first: _apply(_step, p, [xx])
+                )(per_branch, xs)
+            else:
+                stacked = tuple(
+                    jax.tree.map(
+                        lambda *leaves: jnp.stack(leaves),
+                        *[
+                            _stack_params(
+                                params,
+                                [br[k * seg.period + j] for br in seg.branches],
+                            )
+                            for k in range(seg.length)
+                        ],
+                    )
+                    for j in range(seg.period)
+                )
+
+                def body(carry, ps, _phases=phases):
+                    bank_cur, bank_prev = carry
+                    del bank_prev  # freed: this step's output lands there
+                    out = bank_cur
+                    for step, p in zip(_phases, ps):
+                        out = jax.vmap(
+                            lambda pp, xx, _step=step: _apply(_step, pp, [xx])
+                        )(p, out)
+                    return (out, bank_cur), None
+
+                (ys, _), _ = jax.lax.scan(body, (xs, xs), stacked,
+                                          length=seg.length)
+            out_vals: Dict[str, jax.Array] = {}
+            for k, br in enumerate(seg.branches):
+                tail = br[-1]
+                if _prod(ys.shape[1 + nbatch:]) != sizes[tail]:
+                    raise ValueError(
+                        f"segment {seg.branches}: produced {ys.shape} but "
+                        f"plan expects {sizes[tail]} elements"
+                    )
+                out_vals[tail] = ys[k]
+            return out_vals
+        names = seg.branches[0]
+        if len(names) == 1:
+            xs = [vals[src] for src in first.inputs]
+            cur = _apply(first, params.get(first.name, {}), xs)
+        else:
+            cur = vals[first.inputs[0]]
+            stacked = tuple(
+                _stack_params(params, names[j :: seg.period])
                 for j in range(seg.period)
             )
 
@@ -470,50 +507,17 @@ def apply_dag_segment(
                 del bank_prev  # freed: this step's output lands there
                 out = bank_cur
                 for step, p in zip(_phases, ps):
-                    out = jax.vmap(
-                        lambda pp, xx, _step=step: _apply(_step, pp, [xx])
-                    )(p, out)
+                    out = _apply(step, p, [out])
                 return (out, bank_cur), None
 
-            (ys, _), _ = jax.lax.scan(body, (xs, xs), stacked,
-                                      length=seg.length)
-        out_vals: Dict[str, jax.Array] = {}
-        for k, br in enumerate(seg.branches):
-            tail = br[-1]
-            if _prod(ys.shape[1 + nbatch:]) != sizes[tail]:
-                raise ValueError(
-                    f"segment {seg.branches}: produced {ys.shape} but "
-                    f"plan expects {sizes[tail]} elements"
-                )
-            out_vals[tail] = ys[k]
-        return out_vals
-    names = seg.branches[0]
-    if len(names) == 1:
-        xs = [vals[src] for src in first.inputs]
-        cur = _apply(first, params.get(first.name, {}), xs)
-    else:
-        cur = vals[first.inputs[0]]
-        stacked = tuple(
-            _stack_params(params, names[j :: seg.period])
-            for j in range(seg.period)
-        )
-
-        def body(carry, ps, _phases=phases):
-            bank_cur, bank_prev = carry
-            del bank_prev  # freed: this step's output lands there
-            out = bank_cur
-            for step, p in zip(_phases, ps):
-                out = _apply(step, p, [out])
-            return (out, bank_cur), None
-
-        (cur, _), _ = jax.lax.scan(body, (cur, cur), stacked,
-                                   length=seg.length)
-    if _prod(cur.shape[nbatch:]) != sizes[names[-1]]:
-        raise ValueError(
-            f"segment {names}: produced {cur.shape} but plan expects "
-            f"{sizes[names[-1]]} elements"
-        )
-    return {names[-1]: cur}
+            (cur, _), _ = jax.lax.scan(body, (cur, cur), stacked,
+                                       length=seg.length)
+        if _prod(cur.shape[nbatch:]) != sizes[names[-1]]:
+            raise ValueError(
+                f"segment {names}: produced {cur.shape} but plan expects "
+                f"{sizes[names[-1]]} elements"
+            )
+        return {names[-1]: cur}
 
 
 def make_dag_executor(
@@ -572,9 +576,9 @@ def make_dag_executor(
         for v in steps[order[0]].views:
             val = apply_node_fn(v, {}, [val])
         vals: Dict[str, jax.Array] = {order[0]: val}
-        for seg in segments:
+        for i, seg in enumerate(segments):
             vals.update(apply_dag_segment(
-                steps, sizes, seg, params, vals, nbatch,
+                steps, sizes, seg, params, vals, nbatch, index=i,
                 apply_node_fn=apply_node_fn,
             ))
         return vals[mat.output]

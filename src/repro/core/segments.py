@@ -103,6 +103,18 @@ class Segment:
         """True iff the scan body covers more than one phase layer."""
         return self.period > 1
 
+    @property
+    def form(self) -> str:
+        """How the segment executes: ``batched``, ``periodic-scan``,
+        ``scan`` or ``single``."""
+        if self.batched:
+            return "batched"
+        if self.periodic:
+            return "periodic-scan"
+        if self.stacked:
+            return "scan"
+        return "single"
+
 
 def cache_fifo(cache: Dict, key, max_entries: int, build: Callable,
                name: str = ""):

@@ -111,16 +111,6 @@ def build_workload(name: str, *, int8: bool = False, seed: int = 0) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _segment_kind(seg) -> str:
-    if seg.batched:
-        return "batched"
-    if seg.periodic:
-        return "periodic-scan"
-    if seg.length > 1:
-        return "scan"
-    return "single"
-
-
 def _step_cost(step, dtype_bytes: int) -> dict:
     """Static cost of one materialized step: MACs from the layer spec at
     its scheduled input shape, bytes = activations read + written (weights
@@ -154,7 +144,7 @@ def segment_report(graph, plan, *, batch_branches: bool = True) -> dict:
         ]
         rows.append({
             "index": i,
-            "kind": _segment_kind(seg),
+            "kind": seg.form,
             "n_branches": seg.n_branches,
             "length": seg.length,
             "period": seg.period,
@@ -382,9 +372,10 @@ def timed_segments(bundle: dict, *, iters: int = 5, seed: int = 0) -> dict:
 
     rows = []
     for i, seg in enumerate(segs):
-        def seg_fn(params, vals, _seg=seg):
+        def seg_fn(params, vals, _seg=seg, _i=i):
             return pingpong.apply_dag_segment(
-                steps, sizes, _seg, params, vals, 0, apply_node_fn=apply_fn)
+                steps, sizes, _seg, params, vals, 0, index=_i,
+                apply_node_fn=apply_fn)
 
         fn = jax.jit(seg_fn)
         out = fn(params, vals)

@@ -4,13 +4,18 @@ Design constraints (DESIGN.md §11):
 
 * **Low overhead, true no-op when disabled.**  ``tracer.span(...)`` on a
   disabled tracer returns a shared singleton context manager whose
-  ``__enter__``/``__exit__`` do nothing and take no lock; ``instant``/
-  ``counter``/``async_begin``/``async_end`` early-return on one attribute
-  check.  The serving engines read ``self.tracer.enabled`` once per event,
-  so a traced-off engine stays within noise of the untraced PR 6 path
-  (CI-gated in bench-smoke).
-* **Monotonic clocks.**  All timestamps come from ``time.monotonic()``;
-  export rebases to the tracer's construction time so ``ts`` starts near 0.
+  ``__enter__``/``__exit__`` do nothing and take no lock; every other
+  recording method early-returns on one attribute check.  The serving
+  engines read ``self.tracer.enabled`` once per event, so a traced-off
+  engine pays one attribute check per would-be event.
+* **The profiler's clock.**  Every timestamp is ``time.time_ns()``, the
+  epoch clock the JAX profiler stamps its own events with, so a span maps
+  onto a device trace by subtracting the trace's ``profile_start_time``
+  (:meth:`Tracer.spans` returns them on that clock).  ``export``
+  rebases to the tracer's construction time so ``ts`` starts near 0.
+* **Cheap records.**  Events are stored as tuples; the Chrome-JSON dicts
+  are built only by :meth:`Tracer.export`.  Requests are recorded once per
+  batch (:meth:`Tracer.requests`), not twice per request.
 * **Bounded ring buffer.**  At most ``cap`` events are retained (oldest
   dropped first, ``dropped`` counts them) so a long-running engine cannot
   grow memory without bound.
@@ -23,9 +28,11 @@ Design constraints (DESIGN.md §11):
   (``ph: "C"``); thread names are metadata events (``ph: "M"``).
 
 Span taxonomy used by the serving layer (args carry batch id / bucket /
-lane): ``request`` (async, one per rid, queued→done), ``coalesce``,
-``stage``, ``dispatch`` (dispatcher thread), ``device``, ``complete``
-(completer thread), ``prefill``/``decode`` (LLM engine).
+lane / stream): ``request`` (async, one per rid, submitted→done),
+``coalesce``, ``stage``, ``dispatch`` ⊃ {``h2d``, ``launch``}, ``handoff``
+(dispatcher thread), ``device``, ``complete`` (completer thread); ``push``
+⊃ {``h2d``, ``step``, ``sync``, ``d2h``} (stream server, caller's thread);
+``prefill``/``decode`` (LLM engine).
 """
 from __future__ import annotations
 
@@ -35,6 +42,13 @@ import time
 from collections import deque
 from pathlib import Path
 from typing import Optional
+
+_now = time.time_ns
+_tid = threading.get_ident
+
+# Event tuples in the ring: (ph, name, tid, t0_ns, t1_ns, args, extra).
+# "X": t0..t1; "i"/"C": at t0 ("C" args are the series); "R": one batch of
+# requests closed at t0 and done at t1, extra = (rids, submit times).
 
 
 class _NullSpan:
@@ -63,18 +77,12 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        self._t0 = time.monotonic()
+        self._t0 = _now()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.monotonic()
-        self._tracer._record({
-            "ph": "X", "name": self.name,
-            "ts": self._tracer._us(self._t0),
-            "dur": max(0, round((t1 - self._t0) * 1e6)),
-            "tid": threading.get_ident(),
-            **({"args": self.args} if self.args else {}),
-        })
+        self._tracer._record(("X", self.name, _tid(), self._t0, _now(),
+                              self.args, None))
         return False
 
 
@@ -92,21 +100,25 @@ class Tracer:
         self.cap = int(cap)
         self.pid = pid
         self.process_name = process_name
-        self._epoch = time.monotonic()
+        self._epoch = _now()
+        # time.perf_counter() -> this clock: both tick at the same rate
+        # (NTP slews them alike), so one offset holds for the tracer's life.
+        self._perf_offset = self._epoch - time.perf_counter_ns()
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=self.cap)
         self.dropped = 0
         self._thread_names: dict = {}
 
     # -- recording -------------------------------------------------------
-    def _us(self, t: float) -> int:
-        return max(0, round((t - self._epoch) * 1e6))
-
-    def _record(self, ev: dict) -> None:
+    def _record(self, ev: tuple) -> None:
         with self._lock:
             if len(self._events) == self.cap:
                 self.dropped += 1
             self._events.append(ev)
+
+    def perf_ns(self, t: float) -> int:
+        """A ``time.perf_counter()`` reading on this tracer's clock."""
+        return round(t * 1e9) + self._perf_offset
 
     def span(self, name: str, **args):
         """``with tracer.span("stage", batch=3, bucket=8): ...`` — a "X"
@@ -115,66 +127,43 @@ class Tracer:
             return _NULL_SPAN
         return _Span(self, name, args or None)
 
-    def complete(self, name: str, t0: float, t1: Optional[float] = None,
+    def complete(self, name: str, t0: int, t1: Optional[int] = None,
                  **args) -> None:
-        """Record an "X" span retroactively from monotonic timestamps —
+        """Record an "X" span retroactively from ``time.time_ns()`` stamps —
         for spans whose start is only known to be interesting after the
         fact (e.g. ``coalesce``: the wait for the *first* request of a
         batch is idle time, not span time)."""
         if not self.enabled:
             return
-        t1 = time.monotonic() if t1 is None else t1
-        self._record({
-            "ph": "X", "name": name,
-            "ts": self._us(t0),
-            "dur": max(0, round((t1 - t0) * 1e6)),
-            "tid": threading.get_ident(),
-            **({"args": args} if args else {}),
-        })
+        t1 = _now() if t1 is None else t1
+        self._record(("X", name, _tid(), t0, t1,
+                      args or None, None))
 
     def instant(self, name: str, **args) -> None:
         if not self.enabled:
             return
-        self._record({
-            "ph": "i", "name": name, "s": "t",
-            "ts": self._us(time.monotonic()),
-            "tid": threading.get_ident(),
-            **({"args": args} if args else {}),
-        })
+        self._record(("i", name, _tid(), _now(),
+                      None, args or None, None))
 
     def counter(self, name: str, **series) -> None:
         """A "C" counter sample, e.g. ``tracer.counter("queue", depth=4)``.
         Perfetto renders each kwarg as one series on the counter track."""
         if not self.enabled:
             return
-        self._record({
-            "ph": "C", "name": name,
-            "ts": self._us(time.monotonic()),
-            "tid": threading.get_ident(),
-            "args": {k: float(v) for k, v in series.items()},
-        })
+        self._record(("C", name, _tid(), _now(),
+                      None, series, None))
 
-    def async_begin(self, name: str, aid, **args) -> None:
-        """Begin an async ("b") span: overlapping lifetimes (one per request)
-        that can't nest on a single thread track."""
+    def requests(self, rids, t_submit, t_close: int, t_done: int,
+                 **args) -> None:
+        """One record for a batch of requests: ids ``rids`` submitted at
+        ``t_submit`` (``time.time_ns()`` stamps, one per request), taken off
+        the queue as a batch at ``t_close`` and done at ``t_done``.  Exports
+        as one async ``request`` span per id, with ``args`` and its
+        ``lane`` on the end event."""
         if not self.enabled:
             return
-        self._record({
-            "ph": "b", "cat": name, "name": name, "id": str(aid),
-            "ts": self._us(time.monotonic()),
-            "tid": threading.get_ident(),
-            **({"args": args} if args else {}),
-        })
-
-    def async_end(self, name: str, aid, **args) -> None:
-        if not self.enabled:
-            return
-        self._record({
-            "ph": "e", "cat": name, "name": name, "id": str(aid),
-            "ts": self._us(time.monotonic()),
-            "tid": threading.get_ident(),
-            **({"args": args} if args else {}),
-        })
+        self._record(("R", "request", _tid(), t_close, t_done,
+                      args or None, (tuple(rids), tuple(t_submit))))
 
     def name_thread(self, label: str) -> None:
         """Label the calling thread's track in the exported trace."""
@@ -185,31 +174,70 @@ class Tracer:
 
     # -- introspection / export ------------------------------------------
     def events(self):
-        """A consistent copy of the retained events (for tests)."""
+        """A consistent copy of the retained event tuples (for tests)."""
         with self._lock:
             return list(self._events)
 
     def spans(self, name: Optional[str] = None):
-        """Completed "X" spans, optionally filtered by name, each as
-        ``(ts_us, dur_us, event)`` sorted by start time."""
-        out = [(e["ts"], e["dur"], e) for e in self.events()
-               if e["ph"] == "X" and (name is None or e["name"] == name)]
-        return sorted(out, key=lambda t: t[0])
+        """The retained spans, optionally filtered by name, as
+        ``(name, tid, start_ns, end_ns, args)`` on ``time.time_ns()``'s
+        clock (the profiler's), sorted by start.  A batch of requests gives
+        one ``request`` span per request, submitted→done, whose args hold
+        ``batch``/``bucket``/``rid``/``lane`` and ``closed_ns``, when its
+        batch was taken off the queue."""
+        out = []
+        for ph, nm, tid, t0, t1, args, extra in self.events():
+            if name is not None and nm != name:
+                continue
+            if ph == "X":
+                out.append((nm, tid, t0, t1, args or {}))
+            elif ph == "R":
+                for lane, (rid, ts) in enumerate(zip(*extra)):
+                    out.append((nm, tid, ts, t1, {**(args or {}), "rid": rid,
+                                                  "lane": lane,
+                                                  "closed_ns": t0}))
+        out.sort(key=lambda r: r[2])
+        return out
+
+    def _us(self, t: int) -> int:
+        return max(0, (t - self._epoch) // 1000)
 
     def export(self) -> dict:
         """The Chrome trace-event object: ``{"traceEvents": [...]}``."""
         with self._lock:
             events = list(self._events)
             tnames = dict(self._thread_names)
-        out = []
-        out.append({"ph": "M", "name": "process_name", "pid": self.pid,
-                    "tid": 0, "ts": 0,
-                    "args": {"name": self.process_name}})
+        pid, us = self.pid, self._us
+        out = [{"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
+                "ts": 0, "args": {"name": self.process_name}}]
         for tid, label in sorted(tnames.items()):
-            out.append({"ph": "M", "name": "thread_name", "pid": self.pid,
+            out.append({"ph": "M", "name": "thread_name", "pid": pid,
                         "tid": tid, "ts": 0, "args": {"name": label}})
-        for ev in events:
-            out.append({"pid": self.pid, **ev})
+        for ph, name, tid, t0, t1, args, extra in events:
+            ts = us(t0)
+            if ph == "X":
+                ev = {"pid": pid, "ph": "X", "name": name, "ts": ts,
+                      "dur": max(0, us(t1) - ts), "tid": tid}
+            elif ph == "i":
+                ev = {"pid": pid, "ph": "i", "name": name, "s": "t",
+                      "ts": ts, "tid": tid}
+            elif ph == "C":
+                out.append({"pid": pid, "ph": "C", "name": name, "ts": ts,
+                            "tid": tid, "args": {k: float(v) for k, v
+                                                 in args.items()}})
+                continue
+            else:  # "R": one async request span per id
+                end = us(t1)
+                for lane, (rid, t_sub) in enumerate(zip(*extra)):
+                    common = {"pid": pid, "cat": name, "name": name,
+                              "id": str(rid), "tid": tid}
+                    out.append({**common, "ph": "b", "ts": us(t_sub)})
+                    out.append({**common, "ph": "e", "ts": end,
+                                "args": {**(args or {}), "lane": lane}})
+                continue
+            if args:
+                ev["args"] = args
+            out.append(ev)
         meta = {"dropped_events": self.dropped,
                 "retained_events": len(events)}
         return {"traceEvents": out, "otherData": meta}

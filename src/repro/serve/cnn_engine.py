@@ -303,9 +303,9 @@ class CNNEngine:
         self._bank_idx: Dict[int, int] = {b: 0 for b in buckets}
         self._queue: "queue.Queue[CNNRequest]" = queue.Queue()
         # Depth-1 handoff: at most one dispatched-but-uncompleted batch,
-        # as (device value, requests, batch id, bucket).
+        # as (device value, requests, batch id, bucket, close time in ns).
         self._inflight: (
-            "queue.Queue[Tuple[jax.Array, List[CNNRequest], int, int]]"
+            "queue.Queue[Tuple[jax.Array, List[CNNRequest], int, int, int]]"
         ) = queue.Queue(maxsize=1)
         self._stop = threading.Event()
         self._threads: List[threading.Thread] = []
@@ -406,12 +406,6 @@ class CNNEngine:
             rid = self._rid
             self._rid += 1
             req = CNNRequest(rid=rid, x=x, t_submit=time.perf_counter())
-            tr = self.tracer
-            if tr.enabled:
-                # Async span: request lifetimes overlap freely, so they live
-                # on an id-keyed async track, not the submitter's thread track.
-                tr.async_begin("request", rid)
-                tr.counter("queue_depth", depth=self._queue.qsize() + 1)
             self._queue.put(req)
         return req
 
@@ -466,12 +460,14 @@ class CNNEngine:
                 r._fail(error)
                 self._queue.task_done()
 
-    def _coalesce(self) -> List[CNNRequest]:
-        """Take one batch off the queue under the coalescing policy."""
+    def _coalesce(self) -> Tuple[List[CNNRequest], int]:
+        """Take one batch off the queue under the coalescing policy; returns
+        it with the ``time.time_ns()`` stamp of the first request's take."""
         try:
             first = self._queue.get(timeout=0.01)
         except queue.Empty:
-            return []
+            return [], 0
+        t_first = time.time_ns()
         batch = [first]
         deadline = time.perf_counter() + self.policy.max_wait_s
         while len(batch) < self.policy.max_batch:
@@ -487,24 +483,24 @@ class CNNEngine:
                     batch.append(self._queue.get(timeout=timeout))
                 except queue.Empty:
                     break
-        return batch
+        return batch, t_first
 
     def _dispatch_loop(self) -> None:
         self.tracer.name_thread("cnn-engine-dispatch")
         while not (self._stop.is_set() and self._queue.empty()):
-            t_coal = time.monotonic()
-            batch = self._coalesce()
+            batch, t_first = self._coalesce()
             if not batch:
                 continue
             try:
-                self._dispatch(batch, t_coal)
+                self._dispatch(batch, t_first)
             except Exception as e:
                 self._fail(e, batch)
             finally:
                 for _ in batch:
                     self._queue.task_done()
 
-    def _dispatch(self, batch: List[CNNRequest], t_coal: float) -> None:
+    def _dispatch(self, batch: List[CNNRequest], t_first: int) -> None:
+        t_close = time.time_ns()  # the batch is closed: queue wait ends
         if self._failure is not None:
             raise self._failure  # coalesced just before a failure drained
         tr = self.tracer  # re-read: callers may enable tracing mid-run
@@ -512,7 +508,7 @@ class CNNEngine:
         bucket, compiled = self._cache.for_batch(n)
         bid = self.stats.record_batch(bucket, n)
         if tr.enabled:
-            tr.complete("coalesce", t_coal, batch=bid, n=n)
+            tr.complete("coalesce", t_first, t_close, batch=bid, n=n)
             tr.counter("queue_depth", depth=self._queue.qsize())
             tr.counter("batch_occupancy", n=n, bucket=bucket)
         # alternate the two staging banks for this bucket
@@ -529,32 +525,39 @@ class CNNEngine:
         # the device computes batch k.  Under a mesh, H2D is a sharded
         # device_put: each device receives only its batch shard.
         with tr.span("dispatch", batch=bid, bucket=bucket, n=n):
-            if self.data_parallel is not None:
-                x = jax.device_put(bank, self.data_parallel.batch_sharding())
-            else:
-                x = jnp.asarray(bank)
-            y = compiled(self.params, x)
-        self._inflight.put((y, batch, bid, bucket))
-        self.metrics.inc("engine.batches")
-        self.metrics.inc("engine.padded_lanes", bucket - n)
-        self.metrics.observe("engine.batch_occupancy", n)
-        self.metrics.set_gauge("engine.queue_depth", self._queue.qsize())
+            with tr.span("h2d", batch=bid):
+                # From a copy: on the CPU a device array may alias its host
+                # buffer, and this bank is restaged two batches on, while
+                # this batch may still be running.
+                host = bank.copy()
+                if self.data_parallel is not None:
+                    x = jax.device_put(
+                        host, self.data_parallel.batch_sharding())
+                else:
+                    x = jnp.asarray(host)
+            with tr.span("launch", batch=bid):
+                y = compiled(self.params, x)
+        # Blocks while the completer still holds the previous batch.
+        with tr.span("handoff", batch=bid):
+            self._inflight.put((y, batch, bid, bucket, t_close))
 
     def _complete_loop(self) -> None:
         self.tracer.name_thread("cnn-engine-complete")
         while not (self._stop.is_set() and self._inflight.empty()):
             try:
-                y, batch, bid, bucket = self._inflight.get(timeout=0.01)
+                y, batch, bid, bucket, t_close = self._inflight.get(
+                    timeout=0.01)
             except queue.Empty:
                 continue
             try:
-                self._complete(y, batch, bid, bucket)
+                self._complete(y, batch, bid, bucket, t_close)
             except Exception as e:
                 self._fail(e, batch)
             finally:
                 self._inflight.task_done()
 
-    def _complete(self, y, batch: List[CNNRequest], bid: int, bucket: int) -> None:
+    def _complete(self, y, batch: List[CNNRequest], bid: int, bucket: int,
+                  t_close: int) -> None:
         tr = self.tracer
         with tr.span("device", batch=bid, bucket=bucket, n=len(batch)):
             out = np.asarray(y)  # blocks until the device value is ready
@@ -564,12 +567,13 @@ class CNNEngine:
                 r.y = out[i]
                 r.t_done = t_done
                 r._done.set()
-                if tr.enabled:
-                    tr.async_end("request", r.rid, batch=bid,
-                                 bucket=bucket, lane=i)
         self.stats.record_latencies(r.latency_s for r in batch)
-        for r in batch:
-            self.metrics.observe("engine.latency_s", r.latency_s)
+        if tr.enabled:
+            # One record per batch: each request's queue wait (submit →
+            # batch closed) and lifetime, written off the submitter's thread.
+            tr.requests([r.rid for r in batch],
+                        [tr.perf_ns(r.t_submit) for r in batch],
+                        t_close, tr.perf_ns(t_done), batch=bid, bucket=bucket)
 
 
 # ---------------------------------------------------------------------------
@@ -594,7 +598,9 @@ class StreamServer:
     int8 step (int8 frames on the wire, quantize with
     ``quantize.quantize_input``), :meth:`from_graph` the float step.
     ``persistent_cache=True`` enables the disk compilation cache exactly as
-    on :class:`CNNEngine`.
+    on :class:`CNNEngine`.  An enabled ``tracer`` records each ``push`` as a
+    span on the caller's thread with its ``h2d``, ``step``, ``sync`` and
+    (emitting frames only) ``d2h`` parts.
     """
 
     def __init__(
@@ -603,6 +609,7 @@ class StreamServer:
         params,
         *,
         prewarm: bool = True,
+        tracer: Optional[Tracer] = None,
         metrics: Optional[MetricsRegistry] = None,
         persistent_cache: bool = False,
     ):
@@ -610,6 +617,8 @@ class StreamServer:
             enable_persistent_cache()
         self.executor = executor
         self.params = params
+        # Read per push, so a caller may swap in an enabled Tracer.
+        self.tracer = tracer or NULL_TRACER
         self.metrics = metrics or MetricsRegistry("stream_server")
         t0 = time.perf_counter()
         self._step = executor.aot_step(params) if prewarm else executor.step
@@ -656,21 +665,28 @@ class StreamServer:
     def push(self, stream_id: str, frame: np.ndarray) -> Optional[np.ndarray]:
         """Feed one (C, W) frame; returns the new output on emitting frames,
         ``None`` otherwise.  Unknown stream ids are opened implicitly."""
-        with self._lock:
-            state = self._states.get(stream_id)
-        if state is None:
-            self.open(stream_id)
+        tr = self.tracer
+        with tr.span("push", stream=stream_id):
             with self._lock:
-                state = self._states[stream_id]
-        frame = jnp.asarray(np.asarray(frame, self.executor.dtype))
-        state, out, emitted = self._step(self.params, state, frame)
-        with self._lock:
-            self._states[stream_id] = state
-        self.metrics.inc("stream.frames")
-        if bool(emitted):
+                state = self._states.get(stream_id)
+            if state is None:
+                self.open(stream_id)
+                with self._lock:
+                    state = self._states[stream_id]
+            with tr.span("h2d"):
+                frame = jnp.asarray(np.asarray(frame, self.executor.dtype))
+            with tr.span("step"):
+                state, out, emitted = self._step(self.params, state, frame)
+            with self._lock:
+                self._states[stream_id] = state
+            self.metrics.inc("stream.frames")
+            with tr.span("sync"):
+                emitted = bool(emitted)
+            if not emitted:
+                return None
             self.metrics.inc("stream.emissions")
-            return np.asarray(out)
-        return None
+            with tr.span("d2h"):
+                return np.asarray(out)
 
     def peek(self, stream_id: str) -> np.ndarray:
         """The stream's held output (last emission; zero-window head output

@@ -1,10 +1,15 @@
-"""Observability subsystem (ISSUE 7).
+"""Observability subsystem.
 
-Covers the acceptance criteria:
+Covers:
   * the span tracer: recording, Chrome trace-event export schema
     (``validate_chrome_trace`` both accepts real traces and rejects broken
-    ones), bounded ring buffer, and a *true* no-op when disabled —
-    an engine run with a disabled tracer records zero events,
+    ones), bounded ring buffer, a *true* no-op when disabled —
+    an engine run with a disabled tracer records zero events — and spans
+    that land on a JAX profiler trace's clock,
+  * where the engine and the stream server record: only on the engine's
+    threads (nested ``h2d``/``launch``, ``handoff``, per-batch request
+    records, ``coalesce`` from the first request taken) and around each
+    ``push``; per-segment named scopes in the executor's HLO,
   * the metrics registry: counters/gauges/histograms, kind safety, JSON
     snapshots, and the executor-cache / cache_fifo wiring,
   * ``ServeStats``: the cross-thread race fix (locked snapshot) and the
@@ -20,6 +25,7 @@ Covers the acceptance criteria:
 import dataclasses
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -55,16 +61,22 @@ def test_tracer_records_and_exports_valid_chrome_trace():
             pass
     tr.counter("depth", depth=3)
     tr.instant("mark")
-    tr.async_begin("request", 7)
-    tr.async_end("request", 7, lane=0)
+    t = time.perf_counter()
+    tr.requests([7, 8], [tr.perf_ns(t), tr.perf_ns(t + 0.001)],
+                tr.perf_ns(t + 0.002), tr.perf_ns(t + 0.003), batch=0)
     trace = tr.export()
     validate_chrome_trace(trace)
     names = [e["name"] for e in trace["traceEvents"]]
     assert {"outer", "inner", "depth", "mark", "request"} <= set(names)
+    # one async request span per id, its lane on the end event
+    ends = {e["id"]: e for e in trace["traceEvents"] if e["ph"] == "e"}
+    assert ends["7"]["args"] == {"batch": 0, "lane": 0}
+    assert ends["8"]["args"] == {"batch": 0, "lane": 1}
     # inner nests inside outer on the same thread track
-    spans = tr.spans()
-    (t_out, d_out, _), (t_in, d_in, _) = spans[0], spans[1]
-    assert t_out <= t_in and t_in + d_in <= t_out + d_out
+    (_, tid_out, s_out, e_out, a_out), = tr.spans("outer")
+    (_, tid_in, s_in, e_in, _), = tr.spans("inner")
+    assert a_out == {"k": 1}
+    assert tid_out == tid_in and s_out <= s_in and e_in <= e_out
 
 
 def test_tracer_disabled_is_noop():
@@ -73,8 +85,8 @@ def test_tracer_disabled_is_noop():
         pass
     tr.counter("c", v=1)
     tr.instant("i")
-    tr.async_begin("r", 1)
-    tr.async_end("r", 1)
+    tr.complete("c", 0, 1)
+    tr.requests([1], [0], 1, 2)
     assert tr.events() == []
     # the shared null tracer is the same object for every span
     assert NULL_TRACER.span("a") is NULL_TRACER.span("b")
@@ -85,9 +97,35 @@ def test_tracer_ring_buffer_bounded():
     for i in range(10):
         with tr.span(f"s{i}"):
             pass
+    tr.requests([1, 2], [0, 0], 1, 2, batch=0)  # a batch record is one event
     assert len(tr.events()) == 4
-    assert tr.dropped == 6
-    assert tr.export()["otherData"]["dropped_events"] == 6
+    assert tr.dropped == 7
+    assert tr.export()["otherData"]["dropped_events"] == 7
+    assert sorted(r[0] for r in tr.spans()) == [
+        "request", "request", "s7", "s8", "s9"]
+
+
+def test_tracer_spans_land_on_the_profiler_clock(tmp_path):
+    """A span mapped by the trace's ``profile_start_time`` lies on the
+    ``TraceAnnotation`` around the same sleep, within 50 us at both ends."""
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    for i in range(3):
+        with jax.profiler.TraceAnnotation(f"probe{i}"):
+            with tr.span(f"probe{i}"):
+                time.sleep(0.002)
+    jax.profiler.stop_trace()
+    data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    start = next(dict(p.stats)["profile_start_time"] for p in data.planes
+                 if p.name == "Task Environment")
+    marks = {e.name: (e.start_ns, e.end_ns) for p in data.planes
+             for ln in p.lines for e in ln.events if e.name.startswith("probe")}
+    for name, _, t0, t1, _ in tr.spans():
+        a0, a1 = marks[name]
+        assert abs(t0 - start - a0) < 50_000 and abs(t1 - start - a1) < 50_000
 
 
 def test_validate_rejects_malformed_traces():
@@ -265,6 +303,17 @@ def test_engine_disabled_tracer_records_nothing(lenet_engine_parts):
     assert tr.events() == []
 
 
+def _nested(inner, outer):
+    """Spans of ``inner`` each lie inside the ``outer`` span of the same
+    batch on the same thread."""
+    by_batch = {a["batch"]: (tid, s, e) for _, tid, s, e, a in outer}
+    for _, tid, s, e, a in inner:
+        o_tid, o_s, o_e = by_batch[a["batch"]]
+        if tid != o_tid or s < o_s or e > o_e:
+            return False
+    return bool(inner)
+
+
 def test_engine_burst_stage_overlaps_device(lenet_engine_parts):
     """The serving-PR design claim: with the depth-1 inflight queue, the
     dispatcher stages batch k+1 while the completer still blocks on the
@@ -283,8 +332,10 @@ def test_engine_burst_stage_overlaps_device(lenet_engine_parts):
     def batch_arg(ev):
         return ev.get("args", {}).get("batch")
 
-    devices = [(t, t + d, batch_arg(e)) for t, d, e in tr.spans("device")]
-    stages = [(t, t + d, batch_arg(e)) for t, d, e in tr.spans("stage")]
+    devices = [(t, t + d, batch_arg(e)) for t, d, e in
+               ((s["ts"], s["dur"], s) for s in _x_events(tr, "device"))]
+    stages = [(t, t + d, batch_arg(e)) for t, d, e in
+              ((s["ts"], s["dur"], s) for s in _x_events(tr, "stage"))]
     overlaps = [
         (bs, bd)
         for s0, s1, bs in stages
@@ -295,10 +346,44 @@ def test_engine_burst_stage_overlaps_device(lenet_engine_parts):
     # double buffering is broken
     assert overlaps, "no stage(k+1)/device(k) overlap found in a burst"
     # request lifecycle spans carry batch/bucket/lane args
-    ends = [e for e in tr.events() if e["ph"] == "e" and e["name"] == "request"]
+    ends = [e for e in tr.export()["traceEvents"]
+            if e["ph"] == "e" and e["name"] == "request"]
     assert len(ends) == 64
     assert all(
         {"batch", "bucket", "lane"} <= set(e["args"]) for e in ends)
+    # the per-batch records count every request once, queued before closed
+    reqs = tr.spans("request")
+    assert sorted(a["rid"] for *_, a in reqs) == list(range(64))
+    assert all(s <= a["closed_ns"] <= e for _, _, s, e, a in reqs)
+    # H2D and launch nest in dispatch; the handoff to the completer shows
+    dispatch = tr.spans("dispatch")
+    assert _nested(tr.spans("h2d"), dispatch)
+    assert _nested(tr.spans("launch"), dispatch)
+    assert len(tr.spans("handoff")) == run.batches
+    # submit() records nothing: every event is on an engine thread
+    assert threading.get_ident() not in {ev[2] for ev in tr.events()}
+
+
+def _x_events(tr, name):
+    return [e for e in tr.export()["traceEvents"]
+            if e["ph"] == "X" and e["name"] == name]
+
+
+def test_engine_coalesce_starts_at_first_request(lenet_engine_parts):
+    """After the queue stood empty, ``coalesce`` spans the policy's wait
+    for more requests, not the idle time before the first one."""
+    graph, plan, params = lenet_engine_parts
+    tr = Tracer()
+    policy = CoalescePolicy(max_batch=8, max_wait_s=0.002)
+    eng = CNNEngine.from_graph(graph, plan, params, buckets=(8,),
+                               policy=policy, tracer=tr)
+    x = np.zeros((1, 32, 32), np.float32)
+    with eng:
+        eng.submit(x).result(timeout=60)
+        time.sleep(0.05)
+        eng.submit(x).result(timeout=60)
+    _, _, t0, t1, _ = tr.spans("coalesce")[-1]
+    assert (t1 - t0) * 1e-9 <= policy.max_wait_s + 0.005
 
 
 def test_engine_metrics_wired(lenet_engine_parts):
@@ -310,11 +395,88 @@ def test_engine_metrics_wired(lenet_engine_parts):
         _, run = eng.serve(xs)
     snap = eng.metrics.snapshot()
     assert snap["executor_cache.lowerings"]["value"] == 2  # both buckets AOT
-    assert snap["engine.batches"]["value"] == run.batches
-    assert snap["engine.latency_s"]["count"] == 8
     assert snap["engine.prewarm_s"]["value"] == pytest.approx(
         run.prewarm_s)
-    assert snap["engine.batch_occupancy"]["count"] == run.batches
+    # batch and latency accounting lives in ServeStats alone
+    stats = eng.stats.snapshot()
+    assert stats.batches == run.batches >= 2
+    assert len(stats.latencies_s) == stats.requests == 8
+    assert sum(stats.bucket_hist.values()) == stats.batches
+    assert stats.padded_lanes == sum(
+        b * k for b, k in stats.bucket_hist.items()) - 8
+    assert not any(k.startswith("engine.") and k != "engine.prewarm_s"
+                   for k in snap)
+
+
+# ---------------------------------------------------------------------------
+# StreamServer spans
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def ds_cnn_parts():
+    import jax
+
+    from repro.core import nn
+    from repro.core.graph import ds_cnn
+
+    g = ds_cnn()
+    return g, nn.init_params(g.to_sequential(), jax.random.PRNGKey(0))
+
+
+def test_stream_server_push_spans(ds_cnn_parts):
+    from repro.serve.cnn_engine import StreamServer
+
+    g, params = ds_cnn_parts
+    tr = Tracer()
+    srv = StreamServer.from_graph(g, params, prewarm=False, tracer=tr)
+    frame = np.zeros((1, 10), np.float32)
+    outs = [srv.push("a", frame) for _ in range(4)]  # emits every 2nd frame
+    validate_chrome_trace(tr.export())
+    pushes = tr.spans("push")
+    assert len(pushes) == 4 and all(a == {"stream": "a"} for *_, a in pushes)
+
+    def inside(name):
+        """For each push, how many ``name`` spans it holds."""
+        kids = tr.spans(name)
+        return [sum(1 for _, kt, ks, ke, _ in kids
+                    if kt == tid and s <= ks and ke <= e)
+                for _, tid, s, e, _ in pushes]
+
+    for name in ("h2d", "step", "sync"):
+        assert inside(name) == [1, 1, 1, 1], name
+    assert inside("d2h") == [int(o is not None) for o in outs] == [0, 1, 0, 1]
+
+    off = Tracer(enabled=False)
+    srv = StreamServer.from_graph(g, params, prewarm=False, tracer=off)
+    srv.push("b", frame)
+    assert off.events() == []
+
+
+def test_dag_executor_names_every_segment():
+    """The lowered ds_cnn_kws() int8 executor carries one named scope per
+    compiled segment, ``seg<index>.<form>``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import fusion, nn, quantize, schedule, segments
+    from repro.core.graph import ds_cnn_kws
+    from repro.quant.exec import make_int8_executor
+
+    g = ds_cnn_kws()
+    fused = fusion.fuse_dag(g)
+    params = fusion.rename_params(
+        fused, nn.init_params(g, jax.random.PRNGKey(0)))
+    calib = jax.random.normal(jax.random.PRNGKey(1), (8, 1, 49, 10))
+    qm = quantize.quantize_dag(fused, params, calib)
+    plan = schedule.plan_dag(g, io_dtype_bytes=1)
+    fn, qparams = make_int8_executor(qm, plan)
+    text = fn.lower(qparams, jax.ShapeDtypeStruct((4, 1, 49, 10), jnp.int8)
+                    ).as_text(debug_info=True)
+    _, _, segs = segments.segments_for_plan(qm.graph, plan)
+    assert len(segs) >= 2
+    for i, seg in enumerate(segs):
+        assert f"seg{i}.{seg.form}/" in text, (i, seg.form)
 
 
 # ---------------------------------------------------------------------------
