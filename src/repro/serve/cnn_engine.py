@@ -594,13 +594,24 @@ class StreamServer:
     emitting frames (every ``emit_stride``-th — 2 for ``ds_cnn()``) and
     ``None`` in between; ``peek`` reads the stream's held output.
 
+    Whether a frame emits depends only on how many frames the stream has
+    pushed since ``open``, so the host keeps that count beside each
+    stream's device state and decides itself: the device still computes
+    ``phase`` and ``emitted`` for its own ``lax.cond``, but the host never
+    reads them.  A non-emitting ``push`` launches the step and returns
+    without waiting on the device; an emitting one waits once, for the
+    single D2H of the posterior, queued right behind the step.
+
     Numerics follow the wrapped executor: :meth:`from_quantized` serves the
     int8 step (int8 frames on the wire, quantize with
     ``quantize.quantize_input``), :meth:`from_graph` the float step.
     ``persistent_cache=True`` enables the disk compilation cache exactly as
     on :class:`CNNEngine`.  An enabled ``tracer`` records each ``push`` as a
-    span on the caller's thread with its ``h2d``, ``step``, ``sync`` and
-    (emitting frames only) ``d2h`` parts.
+    span on the caller's thread with its ``h2d`` and ``step`` parts and, on
+    emitting frames only, ``d2h`` (the wait for the step and the transfer).
+    ``metrics`` counts ``stream.frames``, ``stream.emissions`` and
+    ``stream.device_waits`` (pushes that blocked on the device: one per
+    emission).
     """
 
     def __init__(
@@ -624,7 +635,9 @@ class StreamServer:
         self._step = executor.aot_step(params) if prewarm else executor.step
         self.prewarm_s = time.perf_counter() - t0 if prewarm else 0.0
         self.metrics.set_gauge("stream.prewarm_s", self.prewarm_s)
-        self._states: Dict[str, dict] = {}
+        self._emit_stride = executor.splan.emit_stride
+        # stream id -> (device state, frames pushed since open)
+        self._states: Dict[str, Tuple[dict, int]] = {}
         self._lock = threading.Lock()
 
     # -- constructors ----------------------------------------------------------
@@ -659,32 +672,42 @@ class StreamServer:
         with self._lock:
             if stream_id in self._states:
                 raise ValueError(f"stream {stream_id!r} already open")
-            self._states[stream_id] = self.executor.init_state(self.params)
+            self._states[stream_id] = (self.executor.init_state(self.params), 0)
         self.metrics.inc("stream.opened")
 
     def push(self, stream_id: str, frame: np.ndarray) -> Optional[np.ndarray]:
         """Feed one (C, W) frame; returns the new output on emitting frames,
-        ``None`` otherwise.  Unknown stream ids are opened implicitly."""
+        ``None`` otherwise.  Unknown stream ids are opened implicitly.
+
+        The n-th frame since ``open`` emits when ``n % emit_stride == 0``,
+        decided on the host.  A non-emitting push returns as soon as the
+        step is launched, before it has run on the device; an emitting one
+        blocks once, on the D2H of the posterior.  The frame is copied
+        before the H2D, so the caller may reuse its buffer on return."""
         tr = self.tracer
         with tr.span("push", stream=stream_id):
             with self._lock:
-                state = self._states.get(stream_id)
-            if state is None:
+                entry = self._states.get(stream_id)
+            if entry is None:
                 self.open(stream_id)
                 with self._lock:
-                    state = self._states[stream_id]
+                    entry = self._states[stream_id]
+            state, n = entry
+            n += 1
             with tr.span("h2d"):
-                frame = jnp.asarray(np.asarray(frame, self.executor.dtype))
+                frame = jnp.asarray(np.array(frame, self.executor.dtype, copy=True))
             with tr.span("step"):
-                state, out, emitted = self._step(self.params, state, frame)
+                state, out, _ = self._step(self.params, state, frame)
+            emits = n % self._emit_stride == 0
+            if emits:
+                out.copy_to_host_async()
             with self._lock:
-                self._states[stream_id] = state
+                self._states[stream_id] = (state, n)
             self.metrics.inc("stream.frames")
-            with tr.span("sync"):
-                emitted = bool(emitted)
-            if not emitted:
+            if not emits:
                 return None
             self.metrics.inc("stream.emissions")
+            self.metrics.inc("stream.device_waits")
             with tr.span("d2h"):
                 return np.asarray(out)
 
@@ -692,11 +715,11 @@ class StreamServer:
         """The stream's held output (last emission; zero-window head output
         before the first)."""
         with self._lock:
-            return np.asarray(self._states[stream_id]["out"])
+            return np.asarray(self._states[stream_id][0]["out"])
 
     def close(self, stream_id: str) -> np.ndarray:
         """Close a stream, returning its final held output."""
         with self._lock:
-            state = self._states.pop(stream_id)
+            state, _ = self._states.pop(stream_id)
         self.metrics.inc("stream.closed")
         return np.asarray(state["out"])

@@ -318,6 +318,78 @@ def test_stream_server_implicit_open_and_peek(ds):
         srv.open("s")
 
 
+def test_stream_server_host_emission_schedule(ds):
+    """The host decides which pushes emit; it must pick exactly the frames
+    the device's ``phase`` picks, and block on the device only for those."""
+    from repro.serve.cnn_engine import StreamServer
+
+    _, _, qm = ds
+    srv = StreamServer.from_quantized(qm)
+    ex, qp = srv.executor, srv.params
+    n = 2 * ex.splan.emit_stride + 1
+    frames = {sid: np.asarray(quantize.quantize_input(
+        qm, jax.random.normal(jax.random.PRNGKey(k), (n, 1, 10))))
+        for k, sid in ((8, "a"), (9, "b"))}
+    got = {"a": [], "b": []}
+    for t in range(n):
+        for sid in ("a", "b"):
+            got[sid].append(srv.push(sid, frames[sid][t]))
+    n_emit = 0
+    for sid in ("a", "b"):
+        _, outs, emitted = ex.run(qp, ex.init_state(qp), jnp.asarray(frames[sid]))
+        emitted = np.asarray(emitted)
+        assert [y is not None for y in got[sid]] == emitted.tolist()
+        for t in np.flatnonzero(emitted):
+            np.testing.assert_array_equal(got[sid][t], np.asarray(outs[t]))
+        n_emit += int(emitted.sum())
+    m = srv.metrics
+    assert m.value("stream.frames") == 2 * n
+    assert m.value("stream.device_waits") == m.value("stream.emissions") == n_emit
+    assert m.value("stream.frames") - m.value("stream.device_waits") == 2 * n - n_emit
+
+
+def test_stream_server_caller_may_reuse_frame_buffer(ds):
+    """A non-emitting push returns before its step has run on the device,
+    so it must not alias the caller's frame: overwriting the buffer right
+    after every push leaves the emissions equal to the oracle's."""
+    from repro.serve.cnn_engine import StreamServer
+
+    g, _, qm = ds
+    srv = StreamServer.from_quantized(qm)
+    frames = np.asarray(quantize.quantize_input(
+        qm, jax.random.normal(jax.random.PRNGKey(10), (48, 1, 10))))
+    ref_outs, ref_em = streaming.sliding_window_reference(
+        g, None, frames,
+        forward_fn=lambda _, w: quantize.simulate_int8_dag_forward(qm, w))
+    buf = np.empty_like(frames[0])
+    for t in range(frames.shape[0]):
+        buf[...] = frames[t]
+        y = srv.push("a", buf)
+        buf[...] = -frames[t] - 1  # the caller's next use of its buffer
+        if ref_em[t]:
+            np.testing.assert_array_equal(y, ref_outs[t])
+        else:
+            assert y is None
+
+
+def test_stream_server_reopen_restarts_phase(ds):
+    """``close`` drops the host's frame count with the state: after an odd
+    number of frames, a reopened stream's first push still does not emit
+    (E = 2), and its second does."""
+    from repro.serve.cnn_engine import StreamServer
+
+    g, params, _ = ds
+    srv = StreamServer.from_graph(g, params, prewarm=False)
+    assert srv.executor.splan.emit_stride == 2
+    frame = np.ones((1, 10), np.float32)
+    for _ in range(3):
+        srv.push("s", frame)
+    srv.close("s")
+    srv.open("s")
+    assert srv.push("s", frame) is None
+    assert srv.push("s", frame) is not None
+
+
 # ---------------------------------------------------------------------------
 # persistent compilation cache
 # ---------------------------------------------------------------------------
