@@ -14,10 +14,11 @@ Three kinds of mix, named by the file's ``kind``:
   same number). Latency runs from the due time.
 * ``streams``: ``streams`` concurrent sessions on ``StreamServer``
   (``from_quantized(...).push``), one ``frame`` every ``hop_ms`` per
-  stream at a uniform random phase (one draw for every seed); latency of
-  an emission runs from the due time of the frame that caused it. The
-  generator, the mix's only thread, spins to each due time, so that no
-  sleep adds lateness of its own.
+  stream at a uniform random phase (one draw for every seed); only for a
+  configuration whose layers form a chain (``StreamServer`` runs no joins).
+  Latency of an emission runs from the due time of the frame that caused
+  it. The generator, the mix's only thread, spins to each due time, so that
+  no sleep adds lateness of its own.
 
 Every runner: ``setup()`` compiles and warms exactly the shapes the mix
 uses; ``window(seconds)`` measures and returns the window's record;
@@ -35,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import netspec
 from repro.core import pingpong
 from repro.quant.exec import make_int8_executor
 from repro.serve.cnn_engine import CNNEngine, CoalescePolicy, StreamServer
@@ -198,6 +200,9 @@ class Streams:
         self.n = int(traffic["streams"])
 
     def setup(self):
+        if not netspec.is_chain(self.cfg):
+            raise ValueError(f"{self.cfg['name']}: streams traffic needs a chain of layers; "
+                             "StreamServer runs no branches or joins")
         c, _, w = self.cfg["input"]
         rng = np.random.default_rng([self.seed, 1])
         self.pool = int8_noise(rng, (int(self.t["pool"]), c, w))

@@ -5,7 +5,9 @@
 (``fusion.fuse_dag``, ``schedule.plan_dag`` at one byte per element), and
 wraps the weights from ``seeded.draw`` in the program's ``QuantizedModel``.
 With ``in_scale = out_scale = 1`` each layer's requantization multiplier is
-the drawn ``m`` itself, so program and reference see the same numbers.
+the drawn ``m`` itself, and each ``add`` becomes a ``QuantizedJoin`` whose
+multipliers are the drawn ``m`` too, so program and reference see the same
+numbers.
 """
 from __future__ import annotations
 
@@ -20,6 +22,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 from repro.core import fusion, schedule  # noqa: E402
 from repro.core import segments as segments_mod  # noqa: E402
 from repro.core.graph import (  # noqa: E402
+    Add,
     AvgPool2d,
     Conv2d,
     DAGGraph,
@@ -27,10 +30,11 @@ from repro.core.graph import (  # noqa: E402
     Flatten,
     Input,
     Linear,
+    MaxPool2d,
     Node,
     ReLU,
 )
-from repro.core.quantize import QuantizedLayer, QuantizedModel  # noqa: E402
+from repro.core.quantize import QuantizedJoin, QuantizedLayer, QuantizedModel  # noqa: E402
 
 from netspec import walk  # noqa: E402
 
@@ -43,43 +47,51 @@ class Built:
 
 
 def graph(cfg: dict) -> DAGGraph:
-    """The configuration as the program's graph, unfused."""
+    """The configuration as the program's graph, unfused. A layer's ReLU is a
+    node of its own, ``<name>_relu``, and what reads the layer reads that."""
     nodes = [Node(Input(shape=tuple(cfg["input"]), name="input"))]
-    prev = "input"
+    output = {"input": "input"}  # entry name -> the node that holds its output
 
-    def add(layer):
-        nonlocal prev
-        nodes.append(Node(layer, (prev,)))
-        prev = layer.name
+    def add(layer, inputs):
+        nodes.append(Node(layer, inputs))
+        return layer.name
 
     for layer in walk(cfg):
         op, name = layer["op"], layer["name"]
+        inputs = tuple(output[src] for src in layer["inputs"])
         geom = dict(kernel_size=tuple(layer.get("kernel", (1, 1))),
                     stride=tuple(layer.get("stride", (1, 1))))
         if op == "conv":
-            add(Conv2d(layer["in_shape"][0], layer["out"], padding=tuple(layer["padding"]),
-                       name=name, **geom))
+            last = add(Conv2d(layer["in_shape"][0], layer["out"],
+                              padding=tuple(layer["padding"]), name=name, **geom), inputs)
         elif op == "dwconv":
-            add(DepthwiseConv2d(layer["in_shape"][0], padding=tuple(layer["padding"]),
-                                name=name, **geom))
+            last = add(DepthwiseConv2d(layer["in_shape"][0], padding=tuple(layer["padding"]),
+                                       name=name, **geom), inputs)
+        elif op == "add":
+            last = add(Add(name=name), inputs)
+        elif op == "maxpool":
+            last = add(MaxPool2d(padding=tuple(layer.get("padding", (0, 0))), name=name,
+                                 **geom), inputs)
         elif op == "avgpool":
-            add(AvgPool2d(name=name, **geom))
+            last = add(AvgPool2d(name=name, **geom), inputs)
         elif op == "fc":
             if len(layer["in_shape"]) > 1:
-                add(Flatten(name="flatten"))
-            add(Linear(int(np.prod(layer["in_shape"])), layer["out"], name=name))
+                inputs = (add(Flatten(name="flatten"), inputs),)
+            last = add(Linear(int(np.prod(layer["in_shape"])), layer["out"], name=name), inputs)
         if layer.get("relu"):
-            add(ReLU(name=f"{name}_relu"))
+            last = add(ReLU(name=f"{name}_relu"), (last,))
+        output[name] = last
     return DAGGraph(nodes)
 
 
 def build(cfg: dict, weights: dict) -> Built:
     """Graph, fused int8 model and arena plan for ``weights``
-    (``{name: {"w", "b", "m"}}``, device or host arrays)."""
+    (``{name: {"w", "b", "m"}}``, only ``m`` for a join; device or host
+    arrays)."""
     g = graph(cfg)
     fused = fusion.fuse_dag(g)
     per_channel = {layer["name"] for layer in walk(cfg) if layer["op"] == "dwconv"}
-    layers = {}
+    layers, joins = {}, {}
     for node in fused.nodes:
         inner = getattr(node.layer, "conv", None) or getattr(node.layer, "linear", None)
         source = inner.name if inner is not None else node.name
@@ -87,14 +99,18 @@ def build(cfg: dict, weights: dict) -> Built:
             continue
         p = weights[source]
         m = np.asarray(p["m"], np.float32)
+        if isinstance(node.layer, Add):
+            joins[node.name] = QuantizedJoin(name=node.name, in_scales=tuple(map(float, m)),
+                                             out_scale=1.0)
+            continue
         layers[node.name] = QuantizedLayer(
             name=node.name, w_q=p["w"], b_q=p["b"],
             w_scale=m if source in per_channel else float(m),
             in_scale=1.0, out_scale=1.0)
-    missing = set(weights) - {q.name.split("+")[0] for q in layers.values()}
+    missing = set(weights) - {q.name.split("+")[0] for q in layers.values()} - set(joins)
     if missing:
         raise ValueError(f"{cfg['name']}: no program layer for {sorted(missing)}")
-    qm = QuantizedModel(graph=fused, input_scale=1.0, layers=layers)
+    qm = QuantizedModel(graph=fused, input_scale=1.0, layers=layers, joins=joins)
     return Built(graph=g, qm=qm, plan=schedule.plan_dag(g, io_dtype_bytes=1))
 
 

@@ -13,11 +13,18 @@ that ``seeded.draw`` made. Every rule:
 * a fused average pool sums the ReLU'd accumulators over each window and
   requantizes once with ``m / f32(kh * kw)``; a standalone one sums the int8
   values and requantizes with ``f32(1) / f32(kh * kw)``;
+* a max pool takes the largest int8 value of each window, padding with -128;
+* an ``add`` requantizes each int8 input with its own multiplier
+  (saturated), sums those in int32, saturates the sum to [-128, 127] and
+  takes its ReLU on that;
 * the classifier flattens the map and gives int8 logits.
 
+Each layer reads its inputs by name (``netspec``); a name holds the layer's
+int8 output after its ReLU.
+
 ``bits=4`` is the control: the same pass with weights, the input and every
-activation rounded to the int4 grid (steps of 16 on the int8 scale), the
-nearest precision below the configuration's int8.
+activation, join and pool output rounded to the int4 grid (steps of 16 on
+the int8 scale), the nearest precision below the configuration's int8.
 """
 from __future__ import annotations
 
@@ -55,26 +62,42 @@ def _window_sum(x, kernel, stride):
     return jax.lax.reduce_window(x, 0, jax.lax.add, (1, 1, kh, kw), (1, 1, sh, sw), "VALID")
 
 
+def _max_pool(x, layer):
+    (kh, kw), (sh, sw) = layer["kernel"], layer["stride"]
+    ph, pw = layer.get("padding", (0, 0))
+    return jax.lax.reduce_window(x, jnp.int32(-128), jax.lax.max, (1, 1, kh, kw),
+                                 (1, 1, sh, sw), ((0, 0), (0, 0), (ph, ph), (pw, pw)))
+
+
 def forward(cfg: dict, weights: dict, x, bits: int = 8):
     """Int8 logits ``(N, classes)`` for int8 images ``x`` ``(N, C, H, W)``."""
     layers = walk(cfg)
     q = _grid(bits)
-    h = q(jnp.asarray(x).astype(jnp.int32))
+    vals = {"input": q(jnp.asarray(x).astype(jnp.int32))}
     for i, layer in enumerate(layers):
-        op = layer["op"]
+        op, name = layer["op"], layer["name"]
+        if layer.get("fused"):
+            continue  # taken with the conv before it
+        h = vals[layer["inputs"][0]]
         if op == "avgpool":
-            if layer.get("fused"):
-                continue  # taken with the conv before it
             kh, kw = layer["kernel"]
             s = _window_sum(h, layer["kernel"], layer["stride"])
-            h = q(_requant(s, jnp.float32(1) / jnp.float32(kh * kw)))
+            vals[name] = q(_requant(s, jnp.float32(1) / jnp.float32(kh * kw)))
             continue
-        p = weights[layer["name"]]
-        w = q(p["w"].astype(jnp.int32))
+        if op == "maxpool":
+            vals[name] = q(_max_pool(h, layer))
+            continue
+        p = weights[name]
         m = p["m"].astype(jnp.float32)
+        if op == "add":
+            acc = sum(_requant(vals[src], m[k]) for k, src in enumerate(layer["inputs"]))
+            acc = jnp.clip(acc, -128, 127)
+            vals[name] = q(jnp.maximum(acc, 0) if layer.get("relu") else acc)
+            continue
+        w = q(p["w"].astype(jnp.int32))
         if op == "fc":
             acc = h.reshape(h.shape[0], -1) @ w.T + p["b"]
-            h = q(_requant(acc, m))
+            vals[name] = q(_requant(acc, m))
             continue
         depthwise = op == "dwconv"
         acc = jax.lax.conv_general_dilated(
@@ -84,7 +107,7 @@ def forward(cfg: dict, weights: dict, x, bits: int = 8):
             feature_group_count=layer["in_shape"][0] if depthwise else 1,
             preferred_element_type=jnp.int32,
         ) + p["b"][None, :, None, None]
-        if layer["relu"]:
+        if layer.get("relu"):
             acc = jnp.maximum(acc, 0)
         if depthwise:
             m = m[:, None, None]
@@ -93,8 +116,9 @@ def forward(cfg: dict, weights: dict, x, bits: int = 8):
             kh, kw = nxt["kernel"]
             acc = _window_sum(acc, nxt["kernel"], nxt["stride"])
             m = m / jnp.float32(kh * kw)
-        h = q(_requant(acc, m))
-    return h.astype(jnp.int8)
+            name = nxt["name"]
+        vals[name] = q(_requant(acc, m))
+    return vals[layers[-1]["name"]].astype(jnp.int8)
 
 
 def logits(cfg: dict, weights: dict, x, bits: int = 8, block: int = 256) -> np.ndarray:

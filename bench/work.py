@@ -3,7 +3,7 @@
 Operations are 2 x MACs of the convolutions and the classifier. Bytes are
 the least any implementation must move: the int8 input and int8 logits of
 every image, plus the parameters once per call (int8 weights, int32 biases,
-f32 multipliers). Activation traffic is left out on purpose, so that the
+f32 multipliers, a join's among them). Activation traffic is left out on purpose, so that the
 roofline reads the same work however the layers are fused or laid out.
 """
 from __future__ import annotations
@@ -25,7 +25,8 @@ def param_bytes(cfg: dict) -> int:
     total = 0
     for layer in walk(cfg):
         if "w_shape" in layer:
-            total += math.prod(layer["w_shape"]) + 4 * layer["w_shape"][0] + 4 * layer["multipliers"]
+            total += math.prod(layer["w_shape"]) + 4 * layer["w_shape"][0]
+        total += 4 * layer.get("multipliers", 0)
     return total
 
 
