@@ -42,6 +42,7 @@ from repro.core.graph import (
     MaxPool2d,
     ReLU,
     SequentialGraph,
+    refuse_batchnorm,
 )
 from repro.core.planner import MemoryPlan
 from repro.core.quantize import REQUANT_C, QuantizedModel
@@ -644,6 +645,7 @@ def generate_c(
     with_main: bool = False,
 ) -> str:
     """Float32 C engine (the paper's LeNet-5 deployment, §3/§4)."""
+    refuse_batchnorm(graph, caller="generate_c")
     e = _Emitter()
     weights = {}
     for layer in graph.layers:
@@ -738,6 +740,7 @@ def generate_c_dag(
     offsets; join nodes render as elementwise adds / contiguous concat
     copies.  The engine must match ``nn.forward_dag`` on the same graph.
     """
+    refuse_batchnorm(graph, caller="generate_c_dag")
     e = _Emitter()
     weights = {}
     for layer in graph.layers:

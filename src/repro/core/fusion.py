@@ -20,10 +20,14 @@ convolution layer").
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List
+
+import jax.numpy as jnp
 
 from repro.core.graph import (
     AvgPool2d,
+    BatchNorm2d,
     Conv2d,
     DAGGraph,
     DepthwiseConv2d,
@@ -265,6 +269,59 @@ def fuse_dag(
     fused = DAGGraph(out, output=rename.get(graph.output, graph.output))
     fused.validate()
     return fused
+
+
+def fold_batchnorm(graph: DAGGraph, params: dict) -> tuple:
+    """Fold every :class:`BatchNorm2d` into the conv before it.
+
+    Per output channel, with ``s = gamma / sqrt(var + eps)``:
+    ``w' = w * s`` and ``b' = (b - mean) * s + beta`` (a conv without a bias
+    counts it as 0 and gains one).  The norm's readers then read the conv.
+    Runs before :func:`fuse_dag`, so a conv → BN → ReLU → pool window fuses
+    like any other.
+
+    Raises ``ValueError`` for a norm whose producer is not a
+    :class:`Conv2d` or :class:`DepthwiseConv2d`, or whose producer's output
+    has another reader (that reader would lose the unnormalized value).
+    Returns ``(graph, params)``; neither holds a norm any more.
+    """
+    by_name = {n.name: n for n in graph.nodes}
+    cons = graph.consumers()
+    params = dict(params)
+    folded: Dict[str, object] = {}  # conv name -> conv spec with a bias
+    rename: Dict[str, str] = {}  # norm name -> conv name
+    for node in graph.nodes:
+        bn = node.layer
+        if not isinstance(bn, BatchNorm2d):
+            continue
+        src = node.inputs[0]
+        conv = by_name[src].layer
+        if not isinstance(conv, _CONV_KINDS):
+            raise ValueError(
+                f"fold_batchnorm: {node.name!r} reads {src!r}, a {conv.kind}; "
+                f"only a Conv2d or DepthwiseConv2d can take a BatchNorm2d in"
+            )
+        others = [c for c in cons[src] if c != node.name]
+        if others or src == graph.output:
+            raise ValueError(
+                f"fold_batchnorm: {src!r} is read by {others or ['the output']} "
+                f"besides {node.name!r}; folding would change what they read"
+            )
+        stats, p = params.pop(node.name), params[src]
+        s = stats["gamma"] / jnp.sqrt(stats["var"] + bn.eps)
+        b = p.get("b")
+        b = jnp.zeros_like(s) if b is None else b
+        params[src] = {"w": p["w"] * s[:, None, None, None],
+                       "b": (b - stats["mean"]) * s + stats["beta"]}
+        folded[src] = dataclasses.replace(conv, bias=True)
+        rename[node.name] = src
+    out = DAGGraph(
+        [Node(folded.get(n.name, n.layer), tuple(rename.get(s, s) for s in n.inputs))
+         for n in graph.nodes if n.name not in rename],
+        output=rename.get(graph.output, graph.output),
+    )
+    out.validate()
+    return out, params
 
 
 def rename_params(fused_graph, params: dict) -> dict:

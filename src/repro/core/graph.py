@@ -205,6 +205,35 @@ class DepthwiseConv2d(LayerSpec):
 
 
 @dataclasses.dataclass(frozen=True)
+class BatchNorm2d(LayerSpec):
+    """Eval-mode batch normalization over the channel axis (PyTorch
+    ``BatchNorm2d``, Keras ``BatchNormalization`` at inference): per channel
+    ``(x - mean) / sqrt(var + eps) * gamma + beta``, the running statistics
+    held as parameters (``gamma``, ``beta``, ``mean``, ``var``).
+
+    Only the float oracle (`repro.core.nn`) evaluates it.  The deployment
+    paths take it folded into the conv before it
+    (`repro.core.fusion.fold_batchnorm`); the planners, executors, quantizer
+    and C emitters refuse a graph that still holds one
+    (:func:`refuse_batchnorm`).
+    """
+
+    channels: int = 0
+    eps: float = 1e-5
+
+    def out_shape(self, in_shape: Shape) -> Shape:
+        if in_shape[0] != self.channels:
+            raise ValueError(
+                f"{self.name or 'BatchNorm2d'}: expected {self.channels} "
+                f"channels, got shape {in_shape}"
+            )
+        return tuple(in_shape)
+
+    def param_count(self) -> int:
+        return 4 * self.channels
+
+
+@dataclasses.dataclass(frozen=True)
 class ReLU(LayerSpec):
     def out_shape(self, in_shape: Shape) -> Shape:
         return in_shape
@@ -709,14 +738,32 @@ class DAGGraph:
         return DAGGraph(nodes)
 
 
+def refuse_batchnorm(graph, *, caller: str) -> None:
+    """Raise if ``graph`` still holds a :class:`BatchNorm2d`, naming the node.
+
+    The planners, executors, quantizer and C emitters run conv weights with
+    any batch norm already folded in (`repro.core.fusion.fold_batchnorm`).
+    """
+    for layer in graph.layers:
+        if isinstance(layer, BatchNorm2d):
+            raise ValueError(
+                f"{caller}: node {layer.name or layer.kind!r} is an unfolded "
+                f"BatchNorm2d; fold it into the conv before it with "
+                f"repro.core.fusion.fold_batchnorm first"
+            )
+
+
 def as_sequential(graph, *, caller: str) -> SequentialGraph:
     """Shared validation/normalization for sequential-only entry points.
 
     ``SequentialGraph`` passes through; a chain-shaped :class:`DAGGraph` is
     normalized via :meth:`DAGGraph.to_sequential`; a branching DAG raises a
     clear :class:`TypeError` pointing at the DAG planner instead of failing
-    later with an opaque shape/attribute crash.
+    later with an opaque shape/attribute crash; so does an unfolded
+    :class:`BatchNorm2d` (:func:`refuse_batchnorm`).
     """
+    if isinstance(graph, (SequentialGraph, DAGGraph)):
+        refuse_batchnorm(graph, caller=caller)
     if isinstance(graph, SequentialGraph):
         return graph
     if isinstance(graph, DAGGraph):
@@ -958,5 +1005,57 @@ def residual_cifar() -> DAGGraph:
         Node(ReLU(name="add_relu"), ("add",)),
         Node(Flatten(name="flatten"), ("add_relu",)),
         Node(Linear(1024, 10, name="fc"), ("flatten",)),
+    ]
+    return DAGGraph(nodes)
+
+
+def resnet8() -> DAGGraph:
+    """ResNet-8, the MLPerf Tiny image-classification reference (Banbury et
+    al., arXiv:2106.07597; EEMBC ``resnet_v1_eembc``), as published: conv,
+    BatchNorm and ReLU, with residual joins.
+
+    A ``(3, 32, 32)`` CIFAR-10 input, a 16-channel 3×3 stem, then three
+    residual stacks of two 3×3 convs at 16, 32 and 64 channels.  Stacks 2
+    and 3 open with a stride-2 conv and join a 1×1 stride-2 shortcut conv of
+    the stack's input; each stack ends in an :class:`Add` and a ReLU.  An
+    8×8 average pool and FC-10 close the net.  Every conv but the two
+    shortcuts is followed by a :class:`BatchNorm2d` at Keras's default
+    ``eps`` of 1e-3.  Stride-2 3×3 convs pad 1 on every side where Keras
+    ``same`` pads 0 above and 1 below: the shapes are the published ones.
+
+    12,501,632 MACs; 78,666 parameters, 77,706 with the BatchNorms folded
+    (`repro.core.fusion.fold_batchnorm`), which gives exactly the graph the
+    benchmark configuration ``resnet8_cifar_int8`` lists.
+    """
+    nodes = [Node(Input(shape=(3, 32, 32), name="input"))]
+
+    def conv_bn(name, src, cin, cout, stride, relu):
+        nodes.append(Node(Conv2d(cin, cout, kernel_size=3, stride=stride,
+                                 padding=1, name=name), (src,)))
+        nodes.append(Node(BatchNorm2d(cout, eps=1e-3, name=f"{name}_bn"), (name,)))
+        if not relu:
+            return f"{name}_bn"
+        nodes.append(Node(ReLU(name=f"{name}_relu"), (f"{name}_bn",)))
+        return f"{name}_relu"
+
+    prev = conv_bn("stem", "input", 3, 16, 1, relu=True)
+    cin = 16
+    for i, cout in enumerate((16, 32, 64), start=1):
+        stride = 1 if i == 1 else 2
+        branch = conv_bn(f"s{i}c1", prev, cin, cout, stride, relu=True)
+        branch = conv_bn(f"s{i}c2", branch, cout, cout, 1, relu=False)
+        if i == 1:
+            joined = (prev, branch)
+        else:
+            nodes.append(Node(Conv2d(cin, cout, kernel_size=1, stride=2,
+                                     name=f"s{i}sc"), (prev,)))
+            joined = (branch, f"s{i}sc")
+        nodes.append(Node(Add(name=f"s{i}add"), joined))
+        nodes.append(Node(ReLU(name=f"s{i}add_relu"), (f"s{i}add",)))
+        prev, cin = f"s{i}add_relu", cout
+    nodes += [
+        Node(AvgPool2d(kernel_size=8, stride=8, name="pool"), (prev,)),
+        Node(Flatten(name="flatten"), ("pool",)),
+        Node(Linear(64, 10, name="fc"), ("flatten",)),
     ]
     return DAGGraph(nodes)

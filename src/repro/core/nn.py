@@ -16,6 +16,7 @@ import numpy as np
 from repro.core.graph import (
     Add,
     AvgPool2d,
+    BatchNorm2d,
     Concat,
     Conv2d,
     DAGGraph,
@@ -142,6 +143,13 @@ def avgpool2d(x: jax.Array, kernel, stride, padding=0) -> jax.Array:
     return sumpool2d(x, kernel, stride, padding) / (kh * kw)
 
 
+def batchnorm2d(x: jax.Array, p, eps: float) -> jax.Array:
+    """Eval-mode batch norm over the channel axis of (C,H,W) or (N,C,H,W):
+    ``(x - mean) / sqrt(var + eps) * gamma + beta``, per channel."""
+    gamma, beta, mean, var = (p[k][:, None, None] for k in ("gamma", "beta", "mean", "var"))
+    return (x - mean) / jnp.sqrt(var + eps) * gamma + beta
+
+
 def _conv_like(conv, p, x: jax.Array) -> jax.Array:
     """Dispatch the conv of a (fused) conv layer: dense or depthwise."""
     if isinstance(conv, DepthwiseConv2d):
@@ -198,6 +206,10 @@ def init_params(graph: SequentialGraph, rng: jax.Array, dtype=jnp.float32) -> Pa
             )
             b = jax.random.uniform(k2, (inner.channels,), dtype, -bound, bound) if inner.bias else None
             params[name] = {"w": w} | ({"b": b} if b is not None else {})
+        elif isinstance(inner, BatchNorm2d):
+            # PyTorch's defaults: identity statistics and affine.
+            ones, zeros = jnp.ones((inner.channels,), dtype), jnp.zeros((inner.channels,), dtype)
+            params[name] = {"gamma": ones, "beta": zeros, "mean": zeros, "var": ones}
         elif isinstance(inner, Linear):
             rng, k1, k2 = jax.random.split(rng, 3)
             bound = 1.0 / np.sqrt(inner.in_features)
@@ -215,6 +227,8 @@ def apply_layer(layer, p, x: jax.Array) -> jax.Array:
         return conv2d(x, p["w"], p.get("b"), layer.stride, layer.padding)
     if isinstance(layer, DepthwiseConv2d):
         return depthwise_conv2d(x, p["w"], p.get("b"), layer.stride, layer.padding)
+    if isinstance(layer, BatchNorm2d):
+        return batchnorm2d(x, p, layer.eps)
     if isinstance(layer, ReLU):
         return jax.nn.relu(x)
     if isinstance(layer, MaxPool2d):

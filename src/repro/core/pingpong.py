@@ -31,6 +31,7 @@ arena.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Dict, Tuple
 
 import jax
@@ -428,7 +429,8 @@ def apply_dag_segment(
     buffer values the segment reads; the returned dict maps each branch
     tail to its produced value.  ``index`` is the segment's position in
     the schedule: its ops run under ``jax.named_scope("seg<index>.<form>")``
-    so the compiled program names every segment.
+    so the compiled program names every segment, and a join step's ops under
+    a nested ``join.<node>`` scope (``seg<index>.single/join.<node>``).
     """
     with jax.named_scope(f"seg{index}.{seg.form}"):
         first = steps[seg.branches[0][0]]
@@ -494,7 +496,9 @@ def apply_dag_segment(
         names = seg.branches[0]
         if len(names) == 1:
             xs = [vals[src] for src in first.inputs]
-            cur = _apply(first, params.get(first.name, {}), xs)
+            with (jax.named_scope(f"join.{first.name}") if seg.join
+                  else contextlib.nullcontext()):
+                cur = _apply(first, params.get(first.name, {}), xs)
         else:
             cur = vals[first.inputs[0]]
             stacked = tuple(

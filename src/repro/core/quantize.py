@@ -34,6 +34,7 @@ from repro.core.graph import (
     ReLU,
     SequentialGraph,
     _pair,
+    refuse_batchnorm,
 )
 from repro.core import nn
 
@@ -162,6 +163,7 @@ def quantize(graph: SequentialGraph, params, calibration_x) -> QuantizedModel:
 
     ``calibration_x``: (N, C, H, W) float batch used for activation ranges.
     """
+    refuse_batchnorm(graph, caller="quantize")
     act_scales = _calibrate_scales(graph, params, calibration_x)
     input_scale = max(float(jnp.max(jnp.abs(calibration_x))), 1e-8) / 127.0
 
@@ -294,6 +296,7 @@ def quantize_dag(graph: DAGGraph, params, calibration_x) -> QuantizedModel:
     requant multiplier; joins (Add/Concat) get one int8→int8 multiplier per
     input (:class:`QuantizedJoin`).
     """
+    refuse_batchnorm(graph, caller="quantize_dag")
     input_scale = max(float(jnp.max(jnp.abs(calibration_x))), 1e-8) / 127.0
     scales: Dict[str, float] = {}
     vals: Dict[str, jax.Array] = {}

@@ -35,7 +35,13 @@ import dataclasses
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from repro.core import fusion as fusion_pass
-from repro.core.graph import DAGGraph, FusedConvPool, SequentialGraph, Shape
+from repro.core.graph import (
+    DAGGraph,
+    FusedConvPool,
+    SequentialGraph,
+    Shape,
+    refuse_batchnorm,
+)
 from repro.core.planner import BufferAssignment, MemoryPlan
 
 _VIEW_KINDS = ("ReLU", "Flatten")
@@ -93,8 +99,10 @@ def materialize_dag(graph: DAGGraph) -> MaterializedDAG:
     A ReLU/Flatten node folds into its input's step iff it is that value's
     *only* consumer (in-place is then safe); otherwise it materializes as a
     copy step of its own.  Step order is the graph's listing order — the
-    naive schedule.
+    naive schedule.  An unfolded :class:`~repro.core.graph.BatchNorm2d`
+    raises: every planner, executor and C emitter of a DAG comes through here.
     """
+    refuse_batchnorm(graph, caller="materialize_dag")
     cons = graph.consumers()
     shapes = graph.shapes()
     alias: Dict[str, str] = {}

@@ -104,6 +104,12 @@ class Segment:
         return self.period > 1
 
     @property
+    def join(self) -> bool:
+        """True iff the segment is one join step (``Add``/``Concat``):
+        joins read several values, so they never stack or batch."""
+        return self.kind in ("Add", "Concat")
+
+    @property
     def form(self) -> str:
         """How the segment executes: ``batched``, ``periodic-scan``,
         ``scan`` or ``single``."""
@@ -469,4 +475,5 @@ def segment_stats(segments: Sequence[Segment]) -> Dict[str, int]:
         "periodic_steps": sum(
             s.steps_per_branch * s.n_branches for s in segments if s.periodic
         ),
+        "join_steps": sum(1 for s in segments if s.join),
     }
